@@ -116,6 +116,9 @@ def main() -> None:
                          "BENCH_temporal.json, or BENCH_temporal.smoke.json "
                          "under --smoke)")
     args = ap.parse_args()
+    from repro import compile_cache
+
+    compile_cache.enable()
     if args.out is None:
         args.out = "BENCH_gp.smoke.json" if args.fast else "BENCH_gp.json"
     if args.serve_out is None:

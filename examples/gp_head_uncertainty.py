@@ -18,6 +18,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs.base import ShapeCell, get_smoke_config
 from repro.core import gp_head
 from repro.core.inference import fit_adam
@@ -38,6 +39,7 @@ def pooled_features(model, params, tokens, cfg):
 
 
 def main() -> None:
+    compile_cache.enable()
     cfg = get_smoke_config("smollm-360m")
     model = model_zoo.build(cfg)
     key = jax.random.PRNGKey(0)

@@ -19,6 +19,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.core.distributed import make_gp_mesh
 from repro.data.synthetic import gplvm_synthetic
 from repro.gp import BayesianGPLVM, get
@@ -40,6 +41,7 @@ def main() -> None:
                     help="latent-recovery bar (smoke-mode CI relaxes it: the "
                          "recovery quality depends on the data draw and N)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     key = jax.random.PRNGKey(0)
     X_true, Y = gplvm_synthetic(key, N=args.n, D=3, Q=1)

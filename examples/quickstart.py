@@ -16,6 +16,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.core.distributed import make_gp_mesh
 from repro.gp import SparseGPRegression, get
 
@@ -34,6 +35,7 @@ def main() -> None:
     ap.add_argument("--max-rmse", type=float, default=0.1,
                     help="accuracy bar (smoke sizes/steps warrant a looser one)")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.pallas and args.backend != "jnp":
         ap.error("--pallas is an alias for --backend pallas; don't pass both")
     backend = "pallas" if args.pallas else args.backend

@@ -18,6 +18,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.gp import SparseGPRegression, get
 
 
@@ -30,6 +31,7 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=150)
     ap.add_argument("--n", type=int, default=2000)
     args = ap.parse_args()
+    compile_cache.enable()
 
     from repro.serve import GPServer
 

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 jax.config.update("jax_enable_x64", True)
 
+from repro import compile_cache
 from repro.gp import get, regression
 
 
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--n", type=int, default=100_000)
     ap.add_argument("--steps", type=int, default=60)
     args = ap.parse_args()
+    compile_cache.enable()
 
     from repro.serve import GPServer
 

@@ -18,6 +18,7 @@ import dataclasses
 
 import jax
 
+from repro import compile_cache
 from repro.configs.base import ModelConfig, ShapeCell
 from repro.data.synthetic import TokenStream
 from repro.launch.mesh import make_host_mesh, make_production_mesh
@@ -42,6 +43,7 @@ def main() -> None:
     ap.add_argument("--mesh", default="host", choices=["host", "pod", "multipod"])
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = CFG_100M
     shape = ShapeCell("e2e", args.seq, args.batch, "train")

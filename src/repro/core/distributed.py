@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import gplvm, svgp
 from repro.gp.kernels import Kernel, default_rbf
 from repro.gp.stats import ExactBatch, suff_stats
@@ -113,7 +112,7 @@ def gplvm_loss_dist(mesh: Mesh, *, kernel: Optional[Kernel] = None,
     gspec = make_param_specs(GPLVM_PARAM_NAMES, mesh)
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(gspec, local_spec),
         out_specs=P(),
@@ -143,7 +142,7 @@ def sgpr_loss_dist(mesh: Mesh, *, kernel: Optional[Kernel] = None,
     gspec = make_param_specs(SGPR_PARAM_NAMES, mesh)
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(gspec, local_spec, local_spec),
         out_specs=P(),
@@ -181,7 +180,7 @@ def sgpr_stats_dist(mesh: Mesh, *, kernel: Optional[Kernel] = None,
     gspec = make_param_specs(SGPR_PARAM_NAMES, mesh)
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(gspec, local_spec, local_spec),
         out_specs=P(),
@@ -206,7 +205,7 @@ def gplvm_stats_dist(mesh: Mesh, *, kernel: Optional[Kernel] = None,
     gspec = make_param_specs(GPLVM_PARAM_NAMES, mesh)
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(gspec, local_spec),
         out_specs=P(),
@@ -224,4 +223,6 @@ def make_gp_mesh(n_devices: int | None = None, axis: str = "data") -> Mesh:
     """1-D data mesh over however many devices exist (1 on this CPU box,
     hundreds of chips in production — the code path is identical)."""
     devs = jax.devices() if n_devices is None else jax.devices()[:n_devices]
-    return compat.make_mesh((len(devs),), (axis,), devices=devs)
+    return jax.make_mesh((len(devs),), (axis,),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=devs)

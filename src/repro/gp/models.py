@@ -49,6 +49,13 @@ def _as_2d(Y: jax.Array) -> jax.Array:
     return Y[:, None] if Y.ndim == 1 else Y
 
 
+def _place_data(mesh: Mesh, *arrays: jax.Array) -> tuple:
+    """Shard per-datapoint arrays over the mesh's data axes, as the
+    shard_map'd losses expect, so no device holds the whole data set."""
+    return tuple(jax.device_put(a, distributed.data_sharded(mesh))
+                 for a in arrays)
+
+
 def _pick_inducing(X: jax.Array, M: int) -> jax.Array:
     """Every (N // M)-th datapoint — the quickstart's deterministic subset."""
     N = X.shape[0]
@@ -250,6 +257,9 @@ class SparseGPRegression(_CollapsedGPModel):
             params = self.init_params(X, Y)
         elif self.kernel is None:
             self.kernel = RBF(params["Z"].shape[1])
+        if self.mesh is not None:
+            X, Y = _place_data(self.mesh, X, Y)
+            params = distributed.shard_gp_params(params, self.mesh)
         self._data = (X, Y)
         self.params = self._optimize(self._loss_fn(), params, (X, Y),
                                      optimizer=optimizer, steps=steps, lr=lr,
@@ -328,6 +338,7 @@ class BayesianGPLVM(_CollapsedGPModel):
                                        np.asarray(Y), self.Q, self.M,
                                        init_X=init_X, kernel=self.kernel)
         if self.mesh is not None:
+            (Y,) = _place_data(self.mesh, Y)
             params = distributed.shard_gp_params(params, self.mesh)
         self._data = (Y,)
         self.params = self._optimize(self._loss_fn(), params, (Y,),

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.suffstats import _dot, _vma
+
 TILE_N = 256
 TILE_M = 128
 
@@ -30,9 +32,7 @@ def _kfu_kernel(xs_ref, zs_ref, o_ref, *, ct=jnp.float32):
     zs = zs_ref[...].astype(ct)  # (TILE_M, Q)
     xn = jnp.sum(xs * xs, axis=-1, keepdims=True)  # (TILE_N, 1)
     zn = jnp.sum(zs * zs, axis=-1)[None, :]  # (1, TILE_M)
-    cross = jax.lax.dot_general(
-        xs, zs, (((1,), (1,)), ((), ())), preferred_element_type=ct
-    )  # MXU: (TILE_N, TILE_M)
+    cross = _dot(xs, zs, ((1,), (1,)), ct)  # MXU: (TILE_N, TILE_M)
     d2 = jnp.maximum(xn + zn - 2.0 * cross, 0.0)
     o_ref[...] = jnp.exp(-0.5 * d2).astype(o_ref.dtype)
 
@@ -71,6 +71,7 @@ def kfu_pallas(
     Zs = jnp.pad((Z / lengthscale).astype(ct), ((0, pad_m), (0, 0)))
 
     grid = (Xs.shape[0] // tile_n, Zs.shape[0] // tile_m)
+    vma = _vma(Xs, Zs)
     out = pl.pallas_call(
         functools.partial(_kfu_kernel, ct=ct),
         grid=grid,
@@ -79,7 +80,7 @@ def kfu_pallas(
             pl.BlockSpec((tile_m, Q), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((tile_n, tile_m), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Xs.shape[0], Zs.shape[0]), ct),
+        out_shape=jax.ShapeDtypeStruct((Xs.shape[0], Zs.shape[0]), ct, vma=vma),
         interpret=interpret,
     )(Xs, Zs)
     return (variance * out[:N, :M]).astype(dtype)

@@ -52,6 +52,7 @@ from repro.kernels.suffstats import (
     suffstats_fused_jnp,
     suffstats_pallas,
     suffstats_vjp_jnp,
+    _vma,
 )
 
 # Test-visible override for `interpret_mode()`: None = detect from the
@@ -131,6 +132,23 @@ def _bwd_dispatch(bwd_backend, n, pallas_fn, jnp_fn):
     return jnp_fn()
 
 
+def _match_vma(*args):
+    """Cast every argument to vary over the same mesh axes.
+
+    Inside `jax.shard_map` a custom_vjp's cotangents must vary over the
+    same axes as its primal inputs. The reverse kernels return per-shard
+    partial cotangents for the global inputs (Z, variance, lengthscale), so
+    those inputs are cast to varying here; the transpose of the cast psums
+    the partials. Outside shard_map nothing varies and this is the
+    identity."""
+    vma = _vma(*args)
+    return tuple(
+        a if jax.typeof(a).vma == vma
+        else jax.lax.pcast(a, tuple(sorted(vma - jax.typeof(a).vma)),
+                           to="varying")
+        for a in args)
+
+
 # ---------------------------------------------------------------------------
 # tuned-block resolution + op-factory cache policy
 # ---------------------------------------------------------------------------
@@ -204,7 +222,7 @@ def kfu(X, Z, variance, lengthscale, *, bwd_backend: str = "auto",
         bwd_block = _tuned_block("psi1_bwd_pallas", X.dtype, Z.shape[0],
                                  X.shape[1])
     return _make_kfu_op(bwd_backend, block, bwd_block)(
-        X, Z, variance, lengthscale)
+        *_match_vma(X, Z, variance, lengthscale))
 
 
 # ---------------------------------------------------------------------------
@@ -246,7 +264,7 @@ def psi1(mu, S, Z, variance, lengthscale, *, bwd_backend: str = "auto",
         bwd_block = _tuned_block("psi1_bwd_pallas", mu.dtype, Z.shape[0],
                                  mu.shape[1])
     return _make_psi1_op(bwd_backend, block, bwd_block)(
-        mu, S, Z, variance, lengthscale)
+        *_match_vma(mu, S, Z, variance, lengthscale))
 
 
 # ---------------------------------------------------------------------------
@@ -288,7 +306,7 @@ def psi2(mu, S, Z, variance, lengthscale, *, bwd_backend: str = "auto",
         bwd_block = _tuned_block("psi2_bwd_pallas", mu.dtype, Z.shape[0],
                                  mu.shape[1])
     return _make_psi2_op(bwd_backend, block, bwd_block)(
-        mu, S, Z, variance, lengthscale)
+        *_match_vma(mu, S, Z, variance, lengthscale))
 
 
 # ---------------------------------------------------------------------------
@@ -353,4 +371,4 @@ def suffstats(mu, S, Y, Z, variance, lengthscale, *,
         bwd_block = _tuned_block("suffstats_bwd_pallas", mu.dtype,
                                  Z.shape[0], mu.shape[1])
     return _make_suffstats_op(bwd_backend, block, bwd_block)(
-        mu, S, Y, Z, variance, lengthscale)
+        *_match_vma(mu, S, Y, Z, variance, lengthscale))

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.suffstats import _psi1_tile
+from repro.kernels.suffstats import _psi1_tile, _vma
 
 TILE_N = 256
 TILE_M = 128
@@ -72,6 +72,7 @@ def psi1_pallas(
     l2 = (lengthscale.astype(ct) ** 2)[None, :]  # (1, Q)
 
     grid = (mu_p.shape[0] // tile_n, Z_p.shape[0] // tile_m)
+    vma = _vma(mu_p, S_p, Z_p, l2)
     out = pl.pallas_call(
         functools.partial(_psi1_kernel, ct=ct),
         grid=grid,
@@ -82,7 +83,7 @@ def psi1_pallas(
             pl.BlockSpec((1, Q), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((tile_n, tile_m), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mu_p.shape[0], Z_p.shape[0]), ct),
+        out_shape=jax.ShapeDtypeStruct((mu_p.shape[0], Z_p.shape[0]), ct, vma=vma),
         interpret=interpret,
     )(mu_p, S_p, Z_p, l2)
     return (variance * out[:N, :M]).astype(dtype)

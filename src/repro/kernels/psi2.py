@@ -12,9 +12,9 @@ shared-memory reduction):
     CUDA's shared-memory tree reduction (TPU grid steps are sequential per
     core, so no synchronization exists or is needed).
   * the (mu - zbar)^2 / d_nq exponent is expanded so the n<->m coupling
-    becomes two MXU matmuls (A1, A2) plus a rank-Q cross term accumulated
-    per-q on the VPU; the final weighted reduction over the datapoint tile is
-    itself an MXU contraction  w(1,TN) @ E(TN, TM*TM).
+    becomes MXU matmuls (two halfterms A1, A2 and the rank-Q cross term as
+    one (TN*TM, Q) x (Q, TM) contraction); the final weighted reduction over
+    the datapoint tile is itself an MXU contraction  w(1,TN) @ E(TN, TM*TM).
   * padded datapoints carry weight 0 (exact masking — they contribute nothing
     to the sum, matching the paper's "sum over exactly N points").
 
@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.suffstats import _psi2_tile
+from repro.kernels.suffstats import _dot, _psi2_prefactor, _psi2_tile, _vma
 
 TILE_N = 32
 TILE_M = 128
@@ -56,10 +56,8 @@ def _psi2_kernel(mu_ref, s_ref, w_ref, z1_ref, z2_ref, l2_ref, o_ref, *,
     _, E = _psi2_tile(mu, S, z1, z2, l2, ct=ct)  # (TN, TM, TM)
 
     # weighted datapoint reduction on the MXU: (1,TN) @ (TN, TM*TM)
-    contrib = jax.lax.dot_general(
-        w.T, E.reshape(tn, tm * tm), (((1,), (0,)), ((), ())),
-        preferred_element_type=ct,
-    ).reshape(tm, tm)
+    contrib = _dot(w.T, E.reshape(tn, tm * tm), ((1,), (0,)), ct
+                   ).reshape(tm, tm)
 
     @pl.when(k == 0)
     def _init():
@@ -102,6 +100,7 @@ def psi2_pallas(
 
     Mp = Z_p.shape[0]
     grid = (Mp // tile_m, Mp // tile_m, mu_p.shape[0] // tile_n)
+    vma = _vma(mu_p, S_p, w, Z_p, l2)
     acc = pl.pallas_call(
         functools.partial(_psi2_kernel, ct=ct),
         grid=grid,
@@ -114,13 +113,10 @@ def psi2_pallas(
             pl.BlockSpec((1, Q), lambda i, j, k: (0, 0)),
         ],
         out_specs=pl.BlockSpec((tile_m, tile_m), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Mp), ct),
+        out_shape=jax.ShapeDtypeStruct((Mp, Mp), ct, vma=vma),
         interpret=interpret,
     )(mu_p, S_p, w, Z_p, Z_p, l2)
 
     # n-independent prefactor: sigma^4 exp(-(z - z')^2 / (4 l^2))
-    zs = Z.astype(ct) / lengthscale.astype(ct)
-    zn = jnp.sum(zs * zs, -1)
-    d2 = jnp.maximum(zn[:, None] + zn[None, :] - 2.0 * zs @ zs.T, 0.0)
-    pref = variance.astype(ct) ** 2 * jnp.exp(-0.25 * d2)
+    pref = _psi2_prefactor(Z, variance, lengthscale, ct)
     return (pref * acc[:M, :M]).astype(dtype)

@@ -70,8 +70,30 @@ TILE_M = 128
 # ---------------------------------------------------------------------------
 
 def _dot(a, b, dims, ct):
+    # HIGHEST: the psi exponents expand (mu - z)^2 into differences of
+    # these products, so a single bf16 MXU pass (the TPU default for f32
+    # operands) would cancel away most of their significant bits
     return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=ct)
+
+
+def _vma(*operands):
+    """The mesh axes a pallas_call's outputs vary over: every axis any
+    operand varies over. Inside `jax.shard_map` (the data-parallel losses)
+    the output avals must say so; outside it this is empty."""
+    return frozenset().union(*(jax.typeof(x).vma for x in operands))
+
+
+def _psi2_prefactor(Z, variance, lengthscale, ct):
+    """The (m, m')-only psi2 factor v^2 exp(-|z_m - z_m'|^2 / (4 l^2)),
+    applied outside the kernels (O(M^2)). Shared by every psi2 forward and
+    reverse wrapper."""
+    zs = Z.astype(ct) / lengthscale.astype(ct)
+    zn = jnp.sum(zs * zs, -1)
+    d2 = jnp.maximum(zn[:, None] + zn[None, :]
+                     - 2.0 * _dot(zs, zs, ((1,), (1,)), ct), 0.0)
+    return variance.astype(ct) ** 2 * jnp.exp(-0.25 * d2)
 
 
 def _psi1_tile(mu, S, z, l2, *, ct):
@@ -95,10 +117,9 @@ def _psi2_tile(mu, S, z1, z2, l2, *, ct):
     weight) for one (TN, TM, TM) tile (suffstats_vjp.md eq. (4)-(6)):
     returns (r (TN, Q), E (TN, TM, TM)).
 
-    The (mu - zbar)^2 exponent is expanded so the n<->m coupling becomes two
-    MXU matmuls (A1, A2) plus a rank-Q cross term accumulated per q on the
-    VPU — same math as kernels/psi2.py. Shared by the forward and reverse
-    kernels (see `_psi1_tile`).
+    The (mu - zbar)^2 exponent is expanded so the n<->m coupling becomes
+    MXU matmuls: two halfterms (A1, A2) and the rank-Q cross term. Shared by
+    the forward and reverse kernels (see `_psi1_tile`).
     """
     tn, q_dim = mu.shape
     tm = z1.shape[0]
@@ -114,10 +135,10 @@ def _psi2_tile(mu, S, z1, z2, l2, *, ct):
 
     A1 = halfterm(z1)
     A2 = halfterm(z2)
-    cross = jnp.zeros((tn, tm, tm), ct)
-    for q in range(q_dim):
-        cross = cross + (r[:, q][:, None, None] * z1[:, q][None, :, None]
-                         * z2[:, q][None, None, :])
+    # cross[n, a, b] = sum_q r[n, q] z1[a, q] z2[b, q]: one (TN*TM, Q) x
+    # (Q, TM) MXU contraction, so no per-q (TN, TM, TM) temporary is live
+    rz1 = (r[:, None, :] * z1[None, :, :]).reshape(tn * tm, q_dim)
+    cross = _dot(rz1, z2, ((1,), (1,)), ct).reshape(tn, tm, tm)
     E = jnp.exp((lognorm2 - c2)[:, :, None] + A1[:, :, None] + A2[:, None, :]
                 - 0.5 * cross)
     return r, E
@@ -296,6 +317,7 @@ def suffstats_pallas(mu, S, Y, Z, variance, lengthscale, *,
     Mp = Z_p.shape[0]
 
     grid = (Mp // tile_m, Mp // tile_m, mu_p.shape[0] // tile_n)
+    vma = _vma(mu_p, S_p, Y_p, w, Z_p, l2)
     acc2, accY = pl.pallas_call(
         functools.partial(_suffstats_kernel, ct=ct),
         grid=grid,
@@ -313,17 +335,13 @@ def suffstats_pallas(mu, S, Y, Z, variance, lengthscale, *,
             pl.BlockSpec((tile_m, D), lambda i, j, kn: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Mp, Mp), ct),
-            jax.ShapeDtypeStruct((Mp, D), ct),
+            jax.ShapeDtypeStruct((Mp, Mp), ct, vma=vma),
+            jax.ShapeDtypeStruct((Mp, D), ct, vma=vma),
         ],
         interpret=interpret,
     )(mu_p, S_p, Y_p, w, Z_p, Z_p, l2)
 
-    zs = Z.astype(ct) / lengthscale.astype(ct)
-    zn = jnp.sum(zs * zs, -1)
-    d2 = jnp.maximum(zn[:, None] + zn[None, :] - 2.0 * zs @ zs.T, 0.0)
-    pref2 = variance.astype(ct) ** 2 * jnp.exp(-0.25 * d2)
-    psi2 = pref2 * acc2[:M, :M]
+    psi2 = _psi2_prefactor(Z, variance, lengthscale, ct) * acc2[:M, :M]
     psiY = variance.astype(ct) * accY[:M]
     return psi2, psiY
 
@@ -450,16 +468,14 @@ def suffstats_bwd_pallas(mu, S, Y, Z, variance, lengthscale, g2, gY, *,
     l2 = (lengthscale.astype(ct) ** 2)[None, :]
     v = variance.astype(ct)
 
-    zs = Z.astype(ct) / lengthscale.astype(ct)
-    zn = jnp.sum(zs * zs, -1)
-    d2 = jnp.maximum(zn[:, None] + zn[None, :] - 2.0 * zs @ zs.T, 0.0)
-    g2p = jnp.pad(g2.astype(ct) * v**2 * jnp.exp(-0.25 * d2),
+    g2p = jnp.pad(g2.astype(ct) * _psi2_prefactor(Z, variance, lengthscale, ct),
                   ((0, pad_m), (0, pad_m)))
     gyv = jnp.pad(v * gY.astype(ct), ((0, pad_m), (0, 0)))
 
     Np = mu_p.shape[0]
     Mp = Z_p.shape[0]
     grid = (Np // tile_n, Mp // tile_m, Mp // tile_m)
+    vma = _vma(mu_p, S_p, Y_p, w, Z_p, l2, g2p, gyv)
     dmu, dS, dY, dZ, dvraw, dl = pl.pallas_call(
         functools.partial(_suffstats_bwd_kernel, tile_m=tile_m, ct=ct),
         grid=grid,
@@ -483,12 +499,12 @@ def suffstats_bwd_pallas(mu, S, Y, Z, variance, lengthscale, g2, gY, *,
             pl.BlockSpec((1, Q), lambda kn, i, j: (0, 0)),  # dl
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Np, Q), ct),
-            jax.ShapeDtypeStruct((Np, Q), ct),
-            jax.ShapeDtypeStruct((Np, D), ct),
-            jax.ShapeDtypeStruct((Mp, Q), ct),
-            jax.ShapeDtypeStruct((1, 1), ct),
-            jax.ShapeDtypeStruct((1, Q), ct),
+            jax.ShapeDtypeStruct((Np, Q), ct, vma=vma),
+            jax.ShapeDtypeStruct((Np, Q), ct, vma=vma),
+            jax.ShapeDtypeStruct((Np, D), ct, vma=vma),
+            jax.ShapeDtypeStruct((Mp, Q), ct, vma=vma),
+            jax.ShapeDtypeStruct((1, 1), ct, vma=vma),
+            jax.ShapeDtypeStruct((1, Q), ct, vma=vma),
         ],
         interpret=interpret,
     )(mu_p, S_p, Y_p, w, Z_p, Z_p, l2, g2p, gyv)
@@ -587,6 +603,7 @@ def psi1_bwd_pallas(mu, S, Z, variance, lengthscale, g, *,
     Np = mu_p.shape[0]
     Mp = Z_p.shape[0]
     grid = (Np // tile_n, Mp // tile_m)
+    vma = _vma(mu_p, S_p, Z_p, l2, gv)
     dmu, dS, dZ, dvraw, dl = pl.pallas_call(
         functools.partial(_psi1_bwd_kernel, tile_m=tile_m, ct=ct),
         grid=grid,
@@ -605,11 +622,11 @@ def psi1_bwd_pallas(mu, S, Z, variance, lengthscale, g, *,
             pl.BlockSpec((1, Q), lambda kn, i: (0, 0)),  # dl
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Np, Q), ct),
-            jax.ShapeDtypeStruct((Np, Q), ct),
-            jax.ShapeDtypeStruct((Mp, Q), ct),
-            jax.ShapeDtypeStruct((1, 1), ct),
-            jax.ShapeDtypeStruct((1, Q), ct),
+            jax.ShapeDtypeStruct((Np, Q), ct, vma=vma),
+            jax.ShapeDtypeStruct((Np, Q), ct, vma=vma),
+            jax.ShapeDtypeStruct((Mp, Q), ct, vma=vma),
+            jax.ShapeDtypeStruct((1, 1), ct, vma=vma),
+            jax.ShapeDtypeStruct((1, Q), ct, vma=vma),
         ],
         interpret=interpret,
     )(mu_p, S_p, Z_p, l2, gv)
@@ -702,15 +719,13 @@ def psi2_bwd_pallas(mu, S, Z, variance, lengthscale, g2, *,
     l2 = (lengthscale.astype(ct) ** 2)[None, :]
     v = variance.astype(ct)
 
-    zs = Z.astype(ct) / lengthscale.astype(ct)
-    zn = jnp.sum(zs * zs, -1)
-    d2 = jnp.maximum(zn[:, None] + zn[None, :] - 2.0 * zs @ zs.T, 0.0)
-    g2p = jnp.pad(g2.astype(ct) * v**2 * jnp.exp(-0.25 * d2),
+    g2p = jnp.pad(g2.astype(ct) * _psi2_prefactor(Z, variance, lengthscale, ct),
                   ((0, pad_m), (0, pad_m)))
 
     Np = mu_p.shape[0]
     Mp = Z_p.shape[0]
     grid = (Np // tile_n, Mp // tile_m, Mp // tile_m)
+    vma = _vma(mu_p, S_p, w, Z_p, l2, g2p)
     dmu, dS, dZ, dvraw, dl = pl.pallas_call(
         functools.partial(_psi2_bwd_kernel, tile_m=tile_m, ct=ct),
         grid=grid,
@@ -731,11 +746,11 @@ def psi2_bwd_pallas(mu, S, Z, variance, lengthscale, g2, *,
             pl.BlockSpec((1, Q), lambda kn, i, j: (0, 0)),  # dl
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Np, Q), ct),
-            jax.ShapeDtypeStruct((Np, Q), ct),
-            jax.ShapeDtypeStruct((Mp, Q), ct),
-            jax.ShapeDtypeStruct((1, 1), ct),
-            jax.ShapeDtypeStruct((1, Q), ct),
+            jax.ShapeDtypeStruct((Np, Q), ct, vma=vma),
+            jax.ShapeDtypeStruct((Np, Q), ct, vma=vma),
+            jax.ShapeDtypeStruct((Mp, Q), ct, vma=vma),
+            jax.ShapeDtypeStruct((1, 1), ct, vma=vma),
+            jax.ShapeDtypeStruct((1, Q), ct, vma=vma),
         ],
         interpret=interpret,
     )(mu_p, S_p, w, Z_p, Z_p, l2, g2p)
@@ -992,11 +1007,8 @@ def psi2_vjp_jnp(mu, S, Z, variance, lengthscale, g2, *, chunk: int = 512):
     ls = lengthscale.astype(dt)
     l2 = (ls**2)[None, :]
     Zc = Z.astype(dt)
-    zs = Zc / ls
-    zn = jnp.sum(zs * zs, -1)
-    d2 = jnp.maximum(zn[:, None] + zn[None, :] - 2.0 * zs @ zs.T, 0.0)
     # fold the (m, m')-only prefactor v^2 exp(zterm) into the cotangent
-    G2p = g2.astype(dt) * v**2 * jnp.exp(-0.25 * d2)  # (M, M)  — eq. (9)
+    G2p = g2.astype(dt) * _psi2_prefactor(Zc, v, ls, dt)  # (M, M) — eq. (9)
 
     pad = (-N) % chunk
     mu_p = jnp.pad(mu.astype(dt), ((0, pad), (0, 0)))

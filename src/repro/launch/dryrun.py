@@ -67,9 +67,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
             t_compile = time.time() - t0
 
         ma = compiled.memory_analysis()
-        from repro import compat
-
-        ca = compat.xla_cost_analysis(compiled) or {}
+        ca = compiled.cost_analysis() or {}
         hlo_text = compiled.as_text()
         # trip-count-aware accounting (XLA's cost_analysis counts scan bodies
         # once — see launch/hlo_cost.py); XLA's raw numbers kept for reference

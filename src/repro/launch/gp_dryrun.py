@@ -39,9 +39,9 @@ def main() -> None:
     args = ap.parse_args()
 
     n_chips = 256 if args.mesh == "pod" else 512
-    from repro import compat
-
-    mesh = compat.make_mesh((n_chips,), ("data",), devices=jax.devices()[:n_chips])
+    mesh = jax.make_mesh((n_chips,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=jax.devices()[:n_chips])
     N, M, Q, D = args.n, args.m, args.q, args.d
 
     params_a = {

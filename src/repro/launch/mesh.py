@@ -13,7 +13,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh
 
-from repro import compat
 
 POD_SHAPE = (16, 16)
 N_PODS = 2
@@ -32,10 +31,13 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             "must set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import (see launch/dryrun.py)"
         )
-    return compat.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_host_mesh() -> Mesh:
     """Whatever devices exist (1 CPU here): for tests/examples; same code path."""
     n = len(jax.devices())
-    return compat.make_mesh((1, n), ("data", "model"))
+    return jax.make_mesh((1, n), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

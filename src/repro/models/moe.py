@@ -24,7 +24,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import dense_init, dt
@@ -160,7 +159,7 @@ def moe_apply_ep(params, x: jax.Array, cfg: ModelConfig, constrain) -> MoEOut:
             aux = jax.lax.psum(aux, batch_axes) / dp
         return y.reshape(x_loc.shape), aux
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(bspec, None, None), P(), P("model", None, None),
@@ -262,7 +261,7 @@ def moe_apply_ep_a2a(params, x: jax.Array, cfg: ModelConfig, constrain) -> MoEOu
         return y.reshape(x_loc.shape), aux
 
     bonly = batch_axes if len(batch_axes) > 1 else (batch_axes[0] if batch_axes else None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(bonly, "model", None), P(), P("model", None, None),

@@ -28,7 +28,6 @@ import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
@@ -249,7 +248,7 @@ def _backbone(
             # keep the saved residual stack in the carry's own dtype: without
             # the barrier XLA hoists the rmsnorm f32-convert into the saved
             # buffer, doubling the remat stack (32 GiB on rwkv6 train_4k).
-            xc = compat.optimization_barrier(xc)
+            xc = jax.lax.optimization_barrier(xc)
             layer_params, layer_state = xs
             out_states = []
             for j in range(len(_seg.windows)):

@@ -8,9 +8,9 @@ process is a pure lookup with zero timing runs. `kernels.ops` consults
 `best_blocks()` for all seven registered kernels, and `chunk="auto"`
 anywhere a chunk is accepted resolves through `best_chunk()`.
 
-Measurement is on by default only on accelerator backends; set REPRO_TUNE=1
-to force it elsewhere (the CI smoke lane does, with a 2-candidate grid via
-$REPRO_TUNE_MAX_CANDIDATES). See docs/tuning.md.
+Tuning is opt-in on every backend: only REPRO_TUNE=1 turns it on (the CI
+smoke lane does, with a 2-candidate grid via $REPRO_TUNE_MAX_CANDIDATES).
+See docs/tuning.md.
 """
 from repro.tune.autotune import (
     MEASURE_PROBLEM,

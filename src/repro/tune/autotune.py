@@ -7,21 +7,19 @@ store (`repro.tune.cache`). Every later use, in this process or any other,
 is a pure lookup: a warm cache performs ZERO timing runs (`timing_runs()`
 is the witness the tests assert on).
 
-Resolution order of `best_blocks` / `best_chunk`:
+Tuning is opt-in on every backend: only `REPRO_TUNE=1` (or the test
+override) turns it on. Without it `best_blocks` / `best_chunk` return the
+fallback (module-default blocks / DEFAULT_CHUNK) at once, reading no cache
+file and starting no stopwatch, so what runs is built only from the
+repository's own files. With it, resolution is:
 
   1. in-process memo (dict hit — the per-training-step cost),
   2. persistent cache file,
-  3. when tuning is `enabled()`: measure, store, return the winner,
-  4. otherwise: memoize the fallback (module-default blocks / DEFAULT_CHUNK)
-     without ever starting a stopwatch.
+  3. measure, store, return the winner.
 
-Measurement is opt-in off-accelerator (`REPRO_TUNE=1` or the test override):
-interpret-mode wall times say nothing about the compiled kernels, and the
-CPU test suite must not pay for micro-benchmarks it cannot use. On TPU/GPU
-backends tuning is on by default — exactly where the measured numbers mean
-something. Cache keys carry `(dtype, M, Q, backend, device_kind)` so winners
-never leak across machines, dtypes, or problem shapes; N is deliberately
-absent (the datapoint axis is streamed — block goodness is N-independent).
+Cache keys carry `(dtype, M, Q, backend, device_kind)` so winners never
+leak across machines, dtypes, or problem shapes; N is deliberately absent
+(the datapoint axis is streamed — block goodness is N-independent).
 """
 from __future__ import annotations
 
@@ -71,17 +69,12 @@ _ITERS = 3
 
 
 def enabled() -> bool:
-    """Is the measuring path live? $REPRO_TUNE wins when set ("0"/"false"/
-    "off" disable, anything else enables); the test override wins over that;
-    otherwise tuning is on exactly on accelerator backends. Disabled keys
-    still resolve through the same lookup path — they just memoize the
-    defaults with zero timing runs."""
+    """Is tuning on? Only when $REPRO_TUNE is "1", on any backend; the test
+    override wins over that. Disabled keys resolve to the defaults without
+    reading the cache file."""
     if _ENABLED_OVERRIDE is not None:
         return bool(_ENABLED_OVERRIDE)
-    env = os.environ.get("REPRO_TUNE")
-    if env is not None and env != "":
-        return env.strip().lower() not in ("0", "false", "off")
-    return jax.default_backend() in ("tpu", "gpu", "cuda", "rocm")
+    return os.environ.get("REPRO_TUNE") == "1"
 
 
 def timing_runs() -> int:
@@ -99,10 +92,7 @@ def clear_memo() -> None:
 
 
 def _device_kind() -> str:
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "unknown"
+    return jax.devices()[0].device_kind
 
 
 def make_key(kind: str, name: str, dtype, m: int, q: int,
@@ -190,7 +180,10 @@ def measure_chunks(candidates, *, n: int, m: int, q: int, d: int,
 
 
 def _resolve(key: str, fallback, measure: Callable[[], Any]):
-    """The shared memo -> file -> measure/store -> fallback ladder."""
+    """The shared fallback-when-disabled -> memo -> file -> measure/store
+    ladder."""
+    if not enabled():
+        return fallback
     path = cache.cache_path()
     memo_key = (path, key)
     with _LOCK:
@@ -201,9 +194,6 @@ def _resolve(key: str, fallback, measure: Callable[[], Any]):
             win = hit["winner"]
             _MEMO[memo_key] = win
             return win
-        if not enabled():
-            _MEMO[memo_key] = fallback
-            return fallback
         value = measure()
         if value is None:
             value = fallback
@@ -269,7 +259,10 @@ def cached_interpret_max_n() -> Optional[int]:
     dispatch threshold (`ops.fused_interpret_max_n`). Nothing writes this
     key automatically; pin it manually in the store under
     ``interpret_max_n|<backend>`` (docs/tuning.md) after measuring where
-    interpret-mode cost crosses the streaming twin on a given host."""
+    interpret-mode cost crosses the streaming twin on a given host. Read
+    only when tuning is `enabled()`."""
+    if not enabled():
+        return None
     key = "|".join(["interpret_max_n", jax.default_backend()])
     path = cache.cache_path()
     memo_key = (path, key)

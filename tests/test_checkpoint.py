@@ -109,8 +109,8 @@ from repro.models.model_zoo import build
 
 cfg = get_smoke_config("smollm-360m")
 params = build(cfg).init(jax.random.PRNGKey(7))
-from repro import compat
-mesh = compat.make_mesh(({dshape}), ("data", "model"))
+mesh = jax.make_mesh(({dshape}), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 if "{phase}" == "save":
     sharded = jax.device_put(params, shd.to_shardings(shd.param_specs(params, mesh), mesh))
     CheckpointManager("{dir}").save(11, {{"params": sharded}}, extra={{"step": 11}})

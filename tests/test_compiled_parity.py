@@ -19,12 +19,15 @@ import pytest
 from repro.analysis.pallas_audit import KERNELS, Problem, registry_entry
 from repro.kernels import ops
 
-pytestmark = [
-    pytest.mark.compiled,
-    pytest.mark.skipif(
-        jax.default_backend() not in ("tpu", "gpu", "cuda", "rocm"),
-        reason="compiled-parity lane needs a TPU/GPU backend"),
-]
+pytestmark = pytest.mark.compiled
+
+
+@pytest.fixture(autouse=True)
+def accelerator():
+    """Asks for the backend when a test runs, not while the module is
+    imported: importing must not start a TPU runtime in every worker."""
+    if jax.default_backend() not in ("tpu", "gpu", "cuda", "rocm"):
+        pytest.skip("compiled-parity lane needs a TPU/GPU backend")
 
 # multi-tile in N and M at the default blocks, small enough to compile fast
 PROBLEM = Problem(N=512, M=256, Q=3, D=2)

@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.launch import hlo_cost
 
 
@@ -15,7 +14,7 @@ def test_matches_xla_on_scan_free_program():
     b = jax.ShapeDtypeStruct((512, 1024), jnp.float32)
     compiled = jax.jit(f).lower(a, b).compile()
     ours = hlo_cost.analyze(compiled.as_text())
-    xla = compat.xla_cost_analysis(compiled)
+    xla = compiled.cost_analysis()
     assert abs(ours.flops - xla["flops"]) / xla["flops"] < 0.01
     assert abs(ours.bytes - xla["bytes accessed"]) / xla["bytes accessed"] < 0.05
 
@@ -34,7 +33,7 @@ def test_scan_bodies_multiplied_by_trip_count():
     expect = 10 * 2 * 128**3
     assert abs(ours.flops - expect) / expect < 0.02
     # XLA's own count misses the multiplier — that's why hlo_cost exists
-    assert compat.xla_cost_analysis(compiled)["flops"] < expect / 5
+    assert compiled.cost_analysis()["flops"] < expect / 5
 
 
 def test_nested_scans():
@@ -88,8 +87,8 @@ sys.path.insert(0, %r)
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch import hlo_cost
-from repro import compat
-mesh = compat.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 def f(w, x):
     def body(c, _):
         h = c @ w  # contraction over the sharded dim => all-reduce per step

@@ -107,8 +107,8 @@ from repro.models import moe as moe_mod
 from repro.parallel import sharding as shd
 
 cfg = dataclasses.replace(get_smoke_config("arctic-480b"), capacity_factor=8.0)
-from repro import compat
-mesh = compat.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 params = moe_mod.moe_init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model), jnp.float32)
 constrain = shd.make_constrain(mesh)

@@ -102,6 +102,11 @@ def test_cache_path_env_override(tune_env):
 # ---------------------------------------------------------------------------
 
 def test_disabled_resolution_returns_defaults_without_timing(tune_env):
+    # disabled tuning ignores even a cached winner: only the committed
+    # default tiles run unless REPRO_TUNE=1
+    key = autotune.make_key("blocks", "psi1_pallas", jnp.float32, 128, 3)
+    cache.store(key, {"winner": [64, 128]}, tune_env)
+    tune.clear_memo()
     before = _runs()
     assert tune.best_blocks("psi1_pallas", dtype=jnp.float32, m=128,
                             q=3) is None
@@ -109,9 +114,22 @@ def test_disabled_resolution_returns_defaults_without_timing(tune_env):
     assert _runs() == before
 
 
-def test_cached_winner_resolves_without_timing(tune_env):
+@pytest.mark.parametrize("value, on", [
+    (None, False), ("1", True), ("0", False), ("true", False), ("", False)])
+def test_enabled_only_with_repro_tune_1(monkeypatch, value, on):
+    """Opt-in on every backend, accelerators included."""
+    monkeypatch.setattr(autotune, "_ENABLED_OVERRIDE", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if value is None:
+        monkeypatch.delenv("REPRO_TUNE", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_TUNE", value)
+    assert tune.enabled() is on
+
+
+def test_cached_winner_resolves_without_timing(tuning_on):
     key = autotune.make_key("blocks", "kfu_pallas", jnp.float32, 128, 3)
-    cache.store(key, {"winner": [64, 128]}, tune_env)
+    cache.store(key, {"winner": [64, 128]}, tuning_on)
     tune.clear_memo()
     before = _runs()
     assert tune.best_blocks("kfu_pallas", dtype=jnp.float32, m=128,
@@ -258,7 +276,7 @@ def test_explicit_block_override_matches_defaults(tune_env):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-9)
 
 
-def test_tuned_winner_is_consulted_by_ops(tune_env, monkeypatch):
+def test_tuned_winner_is_consulted_by_ops(tuning_on, monkeypatch):
     """A cached winner changes which block reaches the Pallas wrapper."""
     seen = {}
     real = ops.kfu_pallas
@@ -268,8 +286,9 @@ def test_tuned_winner_is_consulted_by_ops(tune_env, monkeypatch):
         return real(*args, **kw)
 
     monkeypatch.setattr(ops, "kfu_pallas", spy)
-    key = autotune.make_key("blocks", "kfu_pallas", jnp.float64, 16, 3)
-    cache.store(key, {"winner": [64, 128]}, tune_env)
+    for name in ("kfu_pallas", "psi1_bwd_pallas"):
+        key = autotune.make_key("blocks", name, jnp.float64, 16, 3)
+        cache.store(key, {"winner": [64, 128]}, tuning_on)
     tune.clear_memo()
     X = jnp.ones((8, 3)); Z = jnp.ones((16, 3))
     out = ops.kfu(X, Z, jnp.asarray(1.0), jnp.ones(3))
@@ -327,13 +346,13 @@ def test_chunk_auto_matches_explicit(tune_env):
         suff_stats(kern, params, batch, backend="jnp", chunk="turbo")
 
 
-def test_chunk_auto_uses_cached_winner(tune_env, monkeypatch):
+def test_chunk_auto_uses_cached_winner(tuning_on, monkeypatch):
     from repro.gp import stats as gp_stats
     from repro.gp.kernels import RBF
 
     key = autotune.make_key("chunk", "streaming_suff_stats", jnp.float64,
                             8, 2, extra="backend=jnp")
-    cache.store(key, {"winner": 7}, tune_env)
+    cache.store(key, {"winner": 7}, tuning_on)
     tune.clear_memo()
     kern = RBF(2)
     params = kern.init()
@@ -392,9 +411,9 @@ def test_interpret_threshold_override_hook(tune_env, monkeypatch):
     assert ops.FUSED_INTERPRET_MAX_N == 7
 
 
-def test_interpret_threshold_reads_tune_cache(tune_env):
+def test_interpret_threshold_reads_tune_cache(tuning_on):
     key = "|".join(["interpret_max_n", jax.default_backend()])
-    cache.store(key, {"winner": 512}, tune_env)
+    cache.store(key, {"winner": 512}, tuning_on)
     tune.clear_memo()
     assert tune.cached_interpret_max_n() == 512
     assert ops.fused_interpret_max_n() == 512
