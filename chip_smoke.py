@@ -25,6 +25,8 @@ result. Every phase failure raises, so any failed phase exits non-zero.
 Earlier stdout lines are one JSON object per phase (compile seconds, step
 seconds, reference errors, served shapes); the last line is
 {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}.
+Compile seconds (`backend_compile_s`) are `repro.compile_cache`'s build
+seconds: tracing, lowering, and compiling or reading the persistent cache.
 """
 from __future__ import annotations
 
@@ -69,8 +71,6 @@ REF_ROWS = 65_536
 STEPS = 3
 REQUESTS, REQUEST_ROWS = 8, 64
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
 
 class SmokeFailure(RuntimeError):
     pass
@@ -85,34 +85,14 @@ def log(**fields) -> None:
     print(json.dumps(fields), flush=True)
 
 
-class CompileLog:
-    """Backend compile seconds and persistent-cache hits/misses, read from
-    JAX's monitoring events. On a cache hit the compile event times the
-    cache read instead of a compile."""
+def timed(fn):
+    """(result, wall seconds, compile seconds inside them)."""
+    from repro import compile_cache
 
-    def __init__(self):
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == _COMPILE_EVENT:
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-
-def timed(compiles: CompileLog, fn):
-    """(result, wall seconds, backend compile seconds inside them)."""
-    c0, t0 = compiles.seconds, time.perf_counter()
+    c0, t0 = compile_cache.snapshot()["build_s"], time.perf_counter()
     out = fn()
-    return out, time.perf_counter() - t0, compiles.seconds - c0
+    wall = time.perf_counter() - t0
+    return out, wall, compile_cache.snapshot()["build_s"] - c0
 
 
 def kernel_calls(fn, *args) -> int:
@@ -124,10 +104,9 @@ def all_finite(*arrays) -> bool:
     return all(bool(np.isfinite(np.asarray(a)).all()) for a in arrays)
 
 
-def fit_phase(name, compiles, model, *data, n, **fit_kw):
+def fit_phase(name, model, *data, n, **fit_kw):
     """Fit `model` for STEPS Adam steps; logs compile and step seconds."""
-    _, wall, comp = timed(compiles, lambda: model.fit(*data, steps=STEPS,
-                                                      **fit_kw))
+    _, wall, comp = timed(lambda: model.fit(*data, steps=STEPS, **fit_kw))
     check(len(model.history) == 1 and all_finite(model.history),
           f"{name}: loss not finite: {model.history}")
     log(phase=name, n=n, steps=STEPS, loss=model.history[-1],
@@ -154,7 +133,7 @@ def stats_error(kern, params, batch) -> dict:
     return {field: rel_err(a, b) for field, a, b in zip(ref._fields, fused, ref)}
 
 
-def regression_and_serving(compiles, key, *, n, q, m, d):
+def regression_and_serving(key, *, n, q, m, d):
     """(a): SGPR fit on the fused kernels, then export -> GPServer."""
     from repro.core import distributed
     from repro.core.distributed import make_gp_mesh
@@ -174,11 +153,11 @@ def regression_and_serving(compiles, key, *, n, q, m, d):
     check(calls >= 2, f"regression step holds {calls} Pallas TPU kernels, "
           "expected the fused forward and reverse")
     log(phase="a_kernels", tpu_custom_calls=calls)
-    fit_phase("a_fit", compiles, gp, X, Y, n=n)
+    fit_phase("a_fit", gp, X, Y, n=n)
 
     server = GPServer()
     try:
-        _, wall, comp = timed(compiles, lambda: server.register("airline", gp))
+        _, wall, comp = timed(lambda: server.register("airline", gp))
         log(phase="a_export", wall_s=wall, backend_compile_s=comp)
         Xq = jax.random.normal(kq, (REQUESTS, REQUEST_ROWS, q), jnp.float32)
         half = REQUESTS // 2
@@ -198,7 +177,7 @@ def regression_and_serving(compiles, key, *, n, q, m, d):
     return gp, X, Y
 
 
-def gplvm_fit(compiles, key, *, n, q, m, d, mesh, phase):
+def gplvm_fit(key, *, n, q, m, d, mesh, phase):
     """(b) / (d): BayesianGPLVM fit on the fused kernels."""
     from repro.core import distributed, gplvm
     from repro.data.synthetic import gplvm_synthetic
@@ -215,7 +194,7 @@ def gplvm_fit(compiles, key, *, n, q, m, d, mesh, phase):
     check(calls >= 2, f"GP-LVM step holds {calls} Pallas TPU kernels, "
           "expected the fused forward and reverse")
     log(phase=f"{phase}_kernels", tpu_custom_calls=calls)
-    fit_phase(f"{phase}_fit", compiles, lvm, Y, n=n, key=ki)
+    fit_phase(f"{phase}_fit", lvm, Y, n=n, key=ki)
     return lvm, Y, params
 
 
@@ -245,15 +224,15 @@ def check_spread(name, arr, n_devices):
           f"{len(devices)} devices, shard rows {sorted(rows)}")
 
 
-def four_chips(compiles, key, *, n, q, m, d):
+def four_chips(key, *, n, q, m, d):
     """(d): data-parallel GP-LVM on four chips against one device."""
     from repro.core import distributed
     from repro.core.distributed import make_gp_mesh
 
     mesh4, mesh1 = make_gp_mesh(), make_gp_mesh(1)
     check(len(mesh4.devices.flat) == 4, f"mesh has {mesh4.devices.size} devices")
-    lvm, Y, params4 = gplvm_fit(compiles, key, n=n, q=q, m=m, d=d,
-                                mesh=mesh4, phase="d")
+    lvm, Y, params4 = gplvm_fit(key, n=n, q=q, m=m, d=d, mesh=mesh4,
+                                phase="d")
     check_spread("Y", lvm._data[0], 4)
     for name in ("q_mu", "q_logS"):
         check_spread(name, lvm.params[name], 4)
@@ -268,7 +247,7 @@ def four_chips(compiles, key, *, n, q, m, d):
         step = jax.jit(jax.value_and_grad(loss))
         with jax.default_matmul_precision("highest"):
             (val, grads), wall, comp = timed(
-                compiles, lambda: jax.block_until_ready(step(params, Yd)))
+                lambda: jax.block_until_ready(step(params, Yd)))
         out[label] = (float(val), jax.device_get(grads))
         log(phase=f"d_value_and_grad_{label}dev", loss=float(val),
             backend_compile_s=comp, wall_s=wall - comp,
@@ -307,7 +286,7 @@ def main(argv=None) -> int:
     from repro import compile_cache
 
     cache_dir = compile_cache.enable()
-    compiles = CompileLog()
+    compile_cache.snapshot()  # counting starts here
     kind = devices[0].device_kind
     log(phase="device", platform=platform, device_kind=kind,
         count=len(devices), compile_cache=cache_dir)
@@ -315,16 +294,16 @@ def main(argv=None) -> int:
     key = jax.random.PRNGKey(args.seed)
     ka, kb, kd = jax.random.split(key, 3)
     if args.chips == 4:
-        four_chips(compiles, kd, **GPLVM_4CHIP)
+        four_chips(kd, **GPLVM_4CHIP)
     else:
         from repro.core.distributed import make_gp_mesh
 
-        gp, X, Y = regression_and_serving(compiles, ka, **REGRESSION)
-        lvm, Ylvm, _ = gplvm_fit(compiles, kb, mesh=make_gp_mesh(),
-                                 phase="b", **GPLVM)
+        gp, X, Y = regression_and_serving(ka, **REGRESSION)
+        lvm, Ylvm, _ = gplvm_fit(kb, mesh=make_gp_mesh(), phase="b", **GPLVM)
         reference_check(gp, X, Y, lvm, Ylvm)
-    log(phase="compile_cache", dir=cache_dir, hits=compiles.hits,
-        misses=compiles.misses, backend_compile_s=compiles.seconds)
+    counts = compile_cache.snapshot()
+    log(phase="compile_cache", dir=cache_dir, hits=counts["cache_hits"],
+        misses=counts["cache_misses"], backend_compile_s=counts["build_s"])
     print(json.dumps({"ok": True, "device": {
         "platform": platform, "kind": kind, "count": len(devices)}}),
         flush=True)

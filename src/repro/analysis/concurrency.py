@@ -102,7 +102,9 @@ ALIASES: Dict[str, str] = {"ANL002": "ANL006"}
 #   tune locks    autotune's resolve-measure-store cycle wraps the cache
 #                 file lock;
 #   StateStore    wraps nothing but the checkpoint manager (lock-free);
-#   _registry_lock is a leaf: nothing is ever acquired under it.
+#   _registry_lock is a leaf: nothing is ever acquired under it;
+#   compile_cache._lock is the last leaf: JAX's compile listeners take it
+#                 wherever a compile runs, under any lock above.
 LOCK_HIERARCHY: Tuple[str, ...] = (
     "GPServer._cv",
     "GPServer._budget_lock",
@@ -111,6 +113,7 @@ LOCK_HIERARCHY: Tuple[str, ...] = (
     "repro.tune.cache._LOCK",
     "StateStore._lock",
     "GPServer._registry_lock",
+    "repro.compile_cache._lock",
 )
 
 # Locks whose declared purpose is serializing blocking work (checkpoint

@@ -7,19 +7,54 @@ Two paths, mirroring the paper:
                    reproduces the paper's experiment exactly.
   * `fit_adam`   — SPMD Adam on the distributed bound: no collector node, the
                    production path. Works with any loss(params, *batch).
+
+Host spans on the profiler's clock (no-ops unless a profiler trace is being
+taken): `gp.fit` around a facade's whole `fit` (`fit_span`), and
+`gp.adam.step` around each step call of `fit_adam`. Both carry the `fit` id
+of their call and, on exit, what the call built (`compile_cache.snapshot`
+deltas): non-zero only where a step retraced or read its program back.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
+import itertools
 from typing import Any, Callable, Iterable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.optim import AdamConfig, adam_init, adam_update
 
 PyTree = Any
+
+_fit_ids = itertools.count()
+_fit_id = contextvars.ContextVar("gp_fit_id", default=None)
+
+
+def _built_since(before: dict) -> dict:
+    now = compile_cache.snapshot()
+    return {k: now[k] - before[k] for k in now}
+
+
+@contextlib.contextmanager
+def fit_span(facade: str, optimizer: str, steps: int, rows: int):
+    """The `gp.fit` span of one facade `fit` call: its id and arguments at
+    entry, what it built at exit."""
+    fit_id = next(_fit_ids)
+    token = _fit_id.set(fit_id)
+    before = compile_cache.snapshot()
+    with jax.profiler.TraceAnnotation(
+            "gp.fit", fit=fit_id, facade=facade, optimizer=optimizer,
+            steps=steps, rows=rows) as span:
+        try:
+            yield
+        finally:
+            span.set_metadata(**_built_since(before))
+            _fit_id.reset(token)
 
 
 def fit_adam(
@@ -55,13 +90,20 @@ def fit_adam(
     @functools.partial(jax.jit, donate_argnums=donate_argnums)
     def step(params, state, *batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
-        params, state, _ = adam_update(grads, state, params, config)
+        with jax.named_scope("gp.adam.update"):
+            params, state, _ = adam_update(grads, state, params, config)
         return params, state, loss
 
+    fit_id = _fit_id.get()
+    ids = {} if fit_id is None else {"fit": fit_id}
     history = []
     loss = None
     for i in range(steps):
-        params, state, loss = step(params, state, *data)
+        before = compile_cache.snapshot()
+        with jax.profiler.StepTraceAnnotation("gp.adam.step", step_num=i,
+                                              **ids) as span:
+            params, state, loss = step(params, state, *data)
+            span.set_metadata(**_built_since(before))
         if log_every and i % log_every == 0:
             history.append(float(loss))
             print(f"  step {i:5d}  loss {float(loss):.4f}")

@@ -114,23 +114,24 @@ def collapsed_bound(
       beta: noise precision (scalar).
       D: number of output dimensions.
     """
-    N = stats.n
-    L, LA, c = posterior_factors(Kuu, stats, beta, jitter=jitter)
-    psi2 = 0.5 * (stats.psi2 + stats.psi2.T)
+    with jax.named_scope("gp.epilogue"):
+        N = stats.n
+        L, LA, c = posterior_factors(Kuu, stats, beta, jitter=jitter)
+        psi2 = 0.5 * (stats.psi2 + stats.psi2.T)
 
-    # log|Kuu + beta Psi2| - log|Kuu| (== log|B| of the whitened form)
-    logdetB = 2.0 * (jnp.sum(jnp.log(jnp.diagonal(LA)))
-                     - jnp.sum(jnp.log(jnp.diagonal(L))))
-    # tr(Kuu^-1 Psi2) via the (jittered) Kuu factor
-    tmp = jax.scipy.linalg.solve_triangular(L, psi2, lower=True)
-    A = jax.scipy.linalg.solve_triangular(L, tmp.T, lower=True).T
+        # log|Kuu + beta Psi2| - log|Kuu| (== log|B| of the whitened form)
+        logdetB = 2.0 * (jnp.sum(jnp.log(jnp.diagonal(LA)))
+                         - jnp.sum(jnp.log(jnp.diagonal(L))))
+        # tr(Kuu^-1 Psi2) via the (jittered) Kuu factor
+        tmp = jax.scipy.linalg.solve_triangular(L, psi2, lower=True)
+        A = jax.scipy.linalg.solve_triangular(L, tmp.T, lower=True).T
 
-    logdet_term = 0.5 * D * N * jnp.log(beta / (2.0 * jnp.pi)) - 0.5 * D * logdetB
-    quad_term = -0.5 * beta * stats.yy + 0.5 * beta**2 * jnp.sum(c * c)
-    trace_term = -0.5 * beta * D * stats.psi0 + 0.5 * beta * D * jnp.trace(A)
+        logdet_term = 0.5 * D * N * jnp.log(beta / (2.0 * jnp.pi)) - 0.5 * D * logdetB
+        quad_term = -0.5 * beta * stats.yy + 0.5 * beta**2 * jnp.sum(c * c)
+        trace_term = -0.5 * beta * D * stats.psi0 + 0.5 * beta * D * jnp.trace(A)
 
-    bound = logdet_term + quad_term + trace_term
-    return BoundTerms(bound, logdet_term, quad_term, trace_term, L, LA, c)
+        bound = logdet_term + quad_term + trace_term
+        return BoundTerms(bound, logdet_term, quad_term, trace_term, L, LA, c)
 
 
 class Posterior(NamedTuple):

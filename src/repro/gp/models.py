@@ -252,18 +252,20 @@ class SparseGPRegression(_CollapsedGPModel):
     def fit(self, X: jax.Array, Y: jax.Array, *, optimizer: str = "adam",
             steps: int = 300, lr: float = 3e-2, log_every: int = 0,
             params: Optional[Params] = None) -> "SparseGPRegression":
-        Y = _as_2d(Y)
-        if params is None:
-            params = self.init_params(X, Y)
-        elif self.kernel is None:
-            self.kernel = RBF(params["Z"].shape[1])
-        if self.mesh is not None:
-            X, Y = _place_data(self.mesh, X, Y)
-            params = distributed.shard_gp_params(params, self.mesh)
-        self._data = (X, Y)
-        self.params = self._optimize(self._loss_fn(), params, (X, Y),
-                                     optimizer=optimizer, steps=steps, lr=lr,
-                                     log_every=log_every)
+        with inference.fit_span(type(self).__name__, optimizer, steps,
+                                rows=X.shape[0]):
+            Y = _as_2d(Y)
+            if params is None:
+                params = self.init_params(X, Y)
+            elif self.kernel is None:
+                self.kernel = RBF(params["Z"].shape[1])
+            if self.mesh is not None:
+                X, Y = _place_data(self.mesh, X, Y)
+                params = distributed.shard_gp_params(params, self.mesh)
+            self._data = (X, Y)
+            self.params = self._optimize(self._loss_fn(), params, (X, Y),
+                                         optimizer=optimizer, steps=steps,
+                                         lr=lr, log_every=log_every)
         return self
 
     def predict(self, Xt: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -330,20 +332,23 @@ class BayesianGPLVM(_CollapsedGPModel):
             init_X: Optional[jax.Array] = None,
             key: Optional[jax.Array] = None,
             params: Optional[Params] = None) -> "BayesianGPLVM":
-        Y = _as_2d(Y)
-        if self.kernel is None:
-            self.kernel = RBF(self.Q)
-        if params is None:
-            params = gplvm.init_params(key if key is not None else jax.random.PRNGKey(0),
-                                       np.asarray(Y), self.Q, self.M,
-                                       init_X=init_X, kernel=self.kernel)
-        if self.mesh is not None:
-            (Y,) = _place_data(self.mesh, Y)
-            params = distributed.shard_gp_params(params, self.mesh)
-        self._data = (Y,)
-        self.params = self._optimize(self._loss_fn(), params, (Y,),
-                                     optimizer=optimizer, steps=steps, lr=lr,
-                                     log_every=log_every)
+        with inference.fit_span(type(self).__name__, optimizer, steps,
+                                rows=Y.shape[0]):
+            Y = _as_2d(Y)
+            if self.kernel is None:
+                self.kernel = RBF(self.Q)
+            if params is None:
+                params = gplvm.init_params(
+                    key if key is not None else jax.random.PRNGKey(0),
+                    np.asarray(Y), self.Q, self.M, init_X=init_X,
+                    kernel=self.kernel)
+            if self.mesh is not None:
+                (Y,) = _place_data(self.mesh, Y)
+                params = distributed.shard_gp_params(params, self.mesh)
+            self._data = (Y,)
+            self.params = self._optimize(self._loss_fn(), params, (Y,),
+                                         optimizer=optimizer, steps=steps,
+                                         lr=lr, log_every=log_every)
         return self
 
     def latent(self) -> Tuple[jax.Array, jax.Array]:
