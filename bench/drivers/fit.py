@@ -6,10 +6,11 @@ once and drives it through `setup_steps` Adam steps with one `fit` call
 Those steps compile the step program and time it. The window is then one
 `fit(..., params=<where set-up left off>, steps=k)` call on the same
 object, k sized from the set-up steps to fill `--seconds`; it ends in
-`block_until_ready`. `fit` builds a new jitted step on every call, so the
-timed call retraces it and reads it back from the persistent cache: users
-pay that, so it stays inside the window, and the run reports the
-compile-log counts of the window on an earlier line.
+`block_until_ready`. The facade keeps its step program across `fit`
+calls, so the timed call builds nothing: no trace and no read of the
+persistent cache. Whatever a `fit` call would build stays inside the window,
+since users pay it, and the run reports the compile-log counts of the window
+on an earlier line.
 
 The reference follows both calls from the same start: the set-up steps,
 then the window's k steps from where those left off with a fresh Adam

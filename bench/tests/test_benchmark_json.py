@@ -6,6 +6,7 @@ import re
 import pytest
 
 from bench.harness import cell as cells
+from bench.tests import tiny
 
 ROOT = cells.ROOT
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -100,6 +101,10 @@ def test_cell_finds_its_files_and_reports_enough(w):
     for path in (cells.BENCH / "traffic" / f"{w['traffic']}.json",
                  cells.BENCH / "limits" / f"{w['name']}.json"):
         assert path.is_file(), path
+    sizes = tiny.sizes_file(w["name"])
+    assert sizes.is_file(), f"{sizes}: the cell's CPU sizes are missing"
+    assert {"rehearsal", "control"} <= set(tiny.sizes(w["name"])), (
+        f"{sizes}: needs the keys rehearsal and control")
     e2e = [m["name"] for m in SPEC["end_to_end"]
            if w["name"] in m.get("workloads", [w["name"]])]
     assert "setup_s" in e2e and len(e2e) >= 2
@@ -109,7 +114,15 @@ def test_cell_finds_its_files_and_reports_enough(w):
         assert m["moves"] in e2e
 
 
+def test_every_cpu_sizes_file_names_a_cell():
+    names = {w["name"] for w in SPEC["workloads"]}
+    for path in tiny.SIZES.glob("*.json"):
+        assert path.stem in names, f"{path}: no such cell in BENCHMARK.json"
+
+
 def test_each_pair_of_config_and_traffic_once():
+    """Whatever the chips: a four-chip cell of a deployment brings a
+    configuration of its own (PERF.md section 7)."""
     pairs = [(w["config"], w["traffic"]) for w in SPEC["workloads"]]
     assert len(pairs) == len(set(pairs))
 

@@ -4,7 +4,7 @@ rest of the run, reference and comparison included, is the benchmark's."""
 import jax.numpy as jnp
 import pytest
 
-from bench.tests.tiny import run_cell
+from bench.tests.tiny import on_host_devices, run_cell
 
 TRAIN = ["sgpr-airline.train"]
 
@@ -81,4 +81,17 @@ def _window_one_step(monkeypatch):
 def test_training_fault_is_not_correct(name, fault, monkeypatch):
     fault(monkeypatch)
     line = run_cell(name)
+    assert line["correct"] is False, line["check"]
+
+
+def test_fault_planted_in_a_four_device_child():
+    """A fault reaches a cell that rehearses on four host devices: the
+    child applies it by name before the run, as a fault test file of a
+    four-chip cell would (the stand-in is the one-chip cell run as four)."""
+    from bench.tests import test_rehearsal
+
+    line = on_host_devices(
+        "rehearsal", test_rehearsal.STAND_IN, 4,
+        hooks=(test_rehearsal.AS_FOUR_CHIPS, f"{__name__}:_half_batch"))
+    assert line["device"]["count"] == 4
     assert line["correct"] is False, line["check"]
