@@ -97,7 +97,7 @@ def bound_from_stats(
     kern = default_rbf(kernel, params["Z"].shape[1])
     Kuu = kern.K(params["kern"], params["Z"])
     beta = jnp.exp(params["log_beta"])
-    terms = svgp.collapsed_bound(Kuu, stats, beta, D)
+    terms = svgp.collapsed_bound(Kuu, stats, beta, D, shift=True)
     return terms.bound - kl
 
 

@@ -8,6 +8,13 @@ direct matrix gains PSD mass from beta Psi2 and factors robustly; the
 trace term still uses chol(Kuu + jitter), whose failure mode is additive
 error, not NaN. Jitter is relative to mean(diag Kuu) and dtype-aware.
 
+`shift=True` shifts both factored matrices by M^2 eps of their mean
+diagonal (`cholesky_shift`): the size of an M x M Cholesky's rounding in
+that dtype, below which a pivot can go negative. The Bayesian GP-LVM needs
+it in float32: as its lengthscales grow, Kuu + beta Psi2 reaches a
+condition number past 1 / eps, and the default floor (100 eps) is M^2 / 100
+times too small to keep its Cholesky from breaking down.
+
     L   = chol(Kuu + jitter I)
     LA  = chol(Kuu + beta Psi2 + jitter I)
     c   = LA^-1 PsiY                             (M, D)
@@ -68,20 +75,34 @@ def _jitter_eff(Kuu: jax.Array, jitter: float) -> jax.Array:
     return jitter * boost * jnp.maximum(scale, 1e-12)
 
 
+def cholesky_shift(M: int, dtype) -> float:
+    """M^2 eps: the share of mean(diag A) by which an M x M Cholesky's
+    rounding can move A's eigenvalues (backward error ~ M eps tr A). A
+    diagonal shift of that size keeps every pivot positive."""
+    return M * M * float(jnp.finfo(dtype).eps)
+
+
 def posterior_factors(
     Kuu: jax.Array,
     stats: SuffStats,
     beta: jax.Array,
     *,
     jitter: float = DEFAULT_JITTER,
+    shift: bool = False,
 ) -> PosteriorFactors:
     """Factorize the posterior epilogue from sufficient statistics alone:
     L = chol(Kuu + jit I), LA = chol(Kuu + beta Psi2 + jit I), c = LA^-1 PsiY.
-    O(M^3 + M^2 D); never sees the N datapoints."""
+    O(M^3 + M^2 D); never sees the N datapoints. `shift=True` raises the
+    jitter and A's floor to `cholesky_shift` of their mean diagonals."""
     dtype = Kuu.dtype
     M = Kuu.shape[0]
     eye = jnp.eye(M, dtype=dtype)
     jit_eff = _jitter_eff(Kuu, jitter)
+    eps = jnp.finfo(dtype).eps
+    floor = 100.0 * eps
+    if shift:
+        floor = max(floor, cholesky_shift(M, dtype))
+        jit_eff = jnp.maximum(jit_eff, floor * jnp.mean(jnp.diagonal(Kuu)))
 
     # ONE consistent jittered model: every consumer below works on
     # Kuu_j = Kuu + jit I (mixing different jitters across terms breaks the
@@ -91,9 +112,9 @@ def posterior_factors(
     psi2 = 0.5 * (stats.psi2 + stats.psi2.T)
     Abig = Kuu_j + beta * psi2
     # eps-scaled floor for Psi2's own roundoff (~eps * ||Psi2||): negligible
-    # in f64 (preserves the bound to ~1e-10), adequate in f32.
-    eps = jnp.finfo(dtype).eps
-    LA = jnp.linalg.cholesky(Abig + 100.0 * eps * jnp.mean(jnp.diagonal(Abig)) * eye)
+    # in f64 (preserves the bound to ~1e-10); in f32, `shift` sizes it to
+    # the factorization's own rounding
+    LA = jnp.linalg.cholesky(Abig + floor * jnp.mean(jnp.diagonal(Abig)) * eye)
     c = jax.scipy.linalg.solve_triangular(LA, stats.psiY, lower=True)  # (M, D)
     return PosteriorFactors(L, LA, c)
 
@@ -105,6 +126,7 @@ def collapsed_bound(
     D: int,
     *,
     jitter: float = DEFAULT_JITTER,
+    shift: bool = False,
 ) -> BoundTerms:
     """The paper's eq. (3), evaluated from sufficient statistics.
 
@@ -113,10 +135,12 @@ def collapsed_bound(
       stats: accumulated sufficient statistics (possibly psum'd).
       beta: noise precision (scalar).
       D: number of output dimensions.
+      shift: shift both factored matrices by `cholesky_shift`.
     """
     with jax.named_scope("gp.epilogue"):
         N = stats.n
-        L, LA, c = posterior_factors(Kuu, stats, beta, jitter=jitter)
+        L, LA, c = posterior_factors(Kuu, stats, beta, jitter=jitter,
+                                     shift=shift)
         psi2 = 0.5 * (stats.psi2 + stats.psi2.T)
 
         # log|Kuu + beta Psi2| - log|Kuu| (== log|B| of the whitened form)

@@ -69,6 +69,10 @@ class _CollapsedGPModel:
     driver + the (possibly distributed, possibly streaming) posterior
     statistics pass."""
 
+    # whether the epilogue shifts its factored matrices by the Cholesky's
+    # rounding (`svgp.cholesky_shift`), as the model's loss does
+    _shift = False
+
     def __init__(self, kernel: Optional[Kernel], M: int, *,
                  mesh: Optional[Mesh] = None, backend: str = "jnp",
                  chunk: Optional[Union[int, str]] = None,
@@ -154,7 +158,8 @@ class _CollapsedGPModel:
         p = self.params
         beta = jnp.exp(p["log_beta"])
         factors = svgp.posterior_factors(self.kernel.K(p["kern"], p["Z"]),
-                                         self._fitted_stats(), beta)
+                                         self._fitted_stats(), beta,
+                                         shift=self._shift)
         self._posterior_cache = svgp.optimal_qu(factors, beta)
         return self._posterior_cache
 
@@ -166,7 +171,8 @@ class _CollapsedGPModel:
         from repro.serve.state import build_state
 
         self._require_fitted()
-        return build_state(self.kernel, self.params, self._fitted_stats())
+        return build_state(self.kernel, self.params, self._fitted_stats(),
+                           shift=self._shift)
 
     def elbo(self) -> float:
         """Evidence lower bound (total, not per-datapoint) on the training data."""
@@ -291,6 +297,8 @@ class BayesianGPLVM(_CollapsedGPModel):
         psi1/psi2 kernels — both differentiable via the hand-derived
         reverse passes, kernelized when bwd_backend is "auto"/"pallas".
     """
+
+    _shift = True
 
     def __init__(self, kernel: Optional[Kernel] = None, M: int = 100,
                  Q: Optional[int] = None, *,

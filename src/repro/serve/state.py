@@ -66,18 +66,22 @@ class PosteriorState(NamedTuple):
 
 
 def build_state(kernel: Kernel, params: Params, stats: SuffStats, *,
-                jitter: float = svgp.DEFAULT_JITTER) -> PosteriorState:
+                jitter: float = svgp.DEFAULT_JITTER,
+                shift: bool = False) -> PosteriorState:
     """The O(M^3) refold: statistics -> factorized posterior state.
 
     `params` needs the model keys ("kern", "Z", "log_beta"); extra keys
     (e.g. the GP-LVM's q(X)) are ignored — the state never holds per-
     datapoint parameters. Used both at export time (facade
-    `export_state()`) and after every online update/downdate.
+    `export_state()`) and after every online update/downdate. `shift` is
+    `svgp.posterior_factors`'s: the GP-LVM facade exports with it, as its
+    loss and `posterior()` factor; the online refolds use the default.
     """
     kern_p, Z, log_beta = params["kern"], params["Z"], params["log_beta"]
     beta = jnp.exp(log_beta)
     Kuu = kernel.K(kern_p, Z)
-    factors = svgp.posterior_factors(Kuu, stats, beta, jitter=jitter)
+    factors = svgp.posterior_factors(Kuu, stats, beta, jitter=jitter,
+                                     shift=shift)
     post = svgp.optimal_qu(factors, beta)
     return PosteriorState(kern=kern_p, Z=Z, log_beta=log_beta, stats=stats,
                           L=post.L, LA=post.LA, Kuu_inv_mean=post.Kuu_inv_mean)

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from repro import serve
+from repro.core import svgp
 from repro.core.psi_stats import SuffStats
 from repro.gp import BayesianGPLVM, SparseGPRegression, get, suff_stats
 from repro.gp.stats import ExactBatch
@@ -97,6 +98,24 @@ def test_export_state_gplvm_decodes_like_the_facade():
                                rtol=1e-10, atol=1e-12)
     np.testing.assert_allclose(np.asarray(var_s), np.asarray(var_f),
                                rtol=1e-10, atol=1e-12)
+
+
+def test_export_state_gplvm_keeps_the_shifted_epilogue():
+    """In float32 at M = 48 the GP-LVM's epilogue shifts Kuu and A by
+    M^2 eps of their mean diagonals (`svgp.cholesky_shift`), above the
+    default jitter and floor; the exported state holds the same factors as
+    the facade's posterior."""
+    key = jax.random.PRNGKey(3)
+    from repro.data.synthetic import gplvm_synthetic
+
+    _, Y = gplvm_synthetic(key, N=256, D=4, Q=2)
+    lvm = BayesianGPLVM(kernel=get("rbf")(2), M=48).fit(
+        Y.astype(jnp.float32), steps=3, key=key)
+    assert svgp.cholesky_shift(48, jnp.float32) > 100 * svgp.DEFAULT_JITTER
+    st, post = lvm.export_state(), lvm.posterior()
+    assert post.LA.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(st.L), np.asarray(post.L))
+    np.testing.assert_array_equal(np.asarray(st.LA), np.asarray(post.LA))
 
 
 # ---------------------------------------------------------------------------
